@@ -33,6 +33,8 @@ import subprocess
 import sys
 import time
 
+from repro import compile_cache
+
 
 def _git_sha() -> str:
     """The repo HEAD, so every BENCH_<suite>.json pins the code it
@@ -68,6 +70,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default=None, metavar="DIR",
                     help="write one BENCH_<suite>.json per suite into DIR")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     quick = not args.full
 
     from benchmarks import (accuracy, common, kernels_bench, partitioning,

@@ -6,13 +6,17 @@ This is the workload the paper's "large-scale" claim is about: a single
 O(n²) point op — the scene is tiled into fixed-shape blocks, tiles stream
 through the bucketed serving engine (one executable per bucket, compiled
 in ``warm()`` and excluded from the timings), and logits stitch back by
-owner tile.  Peak RSS is reported per size so the memory trajectory is
-visibly sublinear in n² (tile tensors are O(tile_points), the output is
-O(n)); wall-clock covers tiling + dispatch + stitch.
+owner tile; wall-clock covers tiling + dispatch + stitch.
+
+Every (impl, n) runs in this one process, which holds the device: a child
+started after JAX has touched a TPU could not reach it.  Sizes run in
+ascending order, so the device's peak-bytes watermark read after each size
+(``device.memory_stats()``, reported where the backend has it — the TPU
+does, the CPU does not) is that size's peak.
 
 Rows (see benchmarks/README.md):
   scene/<impl>/n<k>/infer       end-to-end µs; derived points_per_s, tiles,
-                                halo_points, peak_rss_mb
+                                halo_points, peak_device_mb
   scene/<impl>/n<k>/compile     warm() compile seconds (excluded above)
 
 CLI (the CI scene-smoke leg):
@@ -21,25 +25,26 @@ CLI (the CI scene-smoke leg):
 from __future__ import annotations
 
 import argparse
-import multiprocessing
-import resource
 import time
 
+import jax
+
 from benchmarks.common import emit
+from repro import scene
+from repro.data import synthetic
 from repro.kernels import ops as kops
 
 
-def _peak_rss_mb() -> float:
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
+def _peak_device_mb() -> str:
+    """The device's peak bytes in use so far, or "not_measured" where the
+    backend keeps no memory statistics (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    return "not_measured" if peak is None else f"{peak / 2**20:.0f}"
 
 
 def _one_scene(impl, n, tile_points, th, halo, microbatch, mesh):
-    """One (impl, n) measurement; run in its own process (see run())."""
-    import jax
-
-    from repro import scene
-    from repro.data import synthetic
-
+    """One (impl, n) measurement."""
     coords, _ = synthetic.scene(0, n)
     cfg = scene.SceneConfig(tile_points=tile_points, halo=halo, th=th,
                             impl=impl, microbatch=microbatch, mesh=mesh)
@@ -53,8 +58,7 @@ def _one_scene(impl, n, tile_points, th, halo, microbatch, mesh):
     assert logits.shape == (n, cfg.num_classes)
     return {"dt": dt, "compile_s": compile_s, "tiles": plan.num_tiles,
             "halo_points": plan.halo_points, "max_tile": plan.max_tile_n,
-            "peak_rss_mb": _peak_rss_mb(),
-            "backend": jax.default_backend()}
+            "peak_device_mb": _peak_device_mb()}
 
 
 def run(quick: bool = True, impl: str | None = None, *,
@@ -62,25 +66,18 @@ def run(quick: bool = True, impl: str | None = None, *,
         halo: float = 0.1, microbatch: int = 4, mesh: str = "none"):
     impls = ([kops.resolve_impl(impl)] if impl is not None
              else ["xla", "pallas"])
-    ns = ns or ((16_384,) if quick else (16_384, 65_536, 262_144))
-    # One spawned process per (impl, n): ru_maxrss is a process-lifetime
-    # high-watermark, so in-process runs would inherit the peak of every
-    # prior size and flatten the memory-scaling trajectory this suite
-    # exists to show.
-    ctx = multiprocessing.get_context("spawn")
+    ns = sorted(ns or ((16_384,) if quick else (16_384, 65_536, 262_144)))
+    note = "" if jax.default_backend() == "tpu" else "interpret_mode"
     for im in impls:
         for n in ns:
-            with ctx.Pool(1) as pool:
-                m = pool.apply(_one_scene, (im, n, tile_points, th, halo,
-                                            microbatch, mesh))
-            note = "" if m["backend"] == "tpu" else "interpret_mode"
+            m = _one_scene(im, n, tile_points, th, halo, microbatch, mesh)
             emit(f"scene/{im}/n{n}/compile", m["compile_s"] * 1e6,
                  "excluded_from_infer")
             emit(f"scene/{im}/n{n}/infer", m["dt"] * 1e6,
                  f"points_per_s={n / m['dt']:.4g};tiles={m['tiles']};"
                  f"halo_points={m['halo_points']};"
                  f"max_tile={m['max_tile']};"
-                 f"peak_rss_mb={m['peak_rss_mb']:.0f}"
+                 f"peak_device_mb={m['peak_device_mb']}"
                  + (f";{note}" if note and im == "pallas" else ""))
     return ",".join(impls)  # backend(s) that ran, for the JSON meta
 

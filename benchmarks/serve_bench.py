@@ -6,7 +6,7 @@ Each impl serves the same stream: clouds padded to their minimal bucket,
 fixed microbatches, plan cache warmed *before* the stream so latencies
 exclude compile (compile time gets its own row).  With no ``--impl`` both
 backends run, so one ``BENCH_serve.json`` carries the xla and pallas
-trajectories side by side (off-TPU pallas runs in interpret mode —
+trajectories side by side (on the CPU pallas runs in interpret mode —
 correctness path, wall-clock not meaningful).
 
 Rows (see benchmarks/README.md for the schema):

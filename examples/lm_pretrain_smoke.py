@@ -10,7 +10,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.lm import model as M
 from repro.lm import steps as steps_lib
 from repro.train import loop as loop_lib
@@ -28,6 +28,7 @@ def main():
     ap.add_argument("--compression", default="none",
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.lm_reduced(args.arch)
     opt_cfg = opt_lib.OptConfig(lr=1e-3, warmup=10,

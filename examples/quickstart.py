@@ -6,8 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import core
+from repro import compile_cache, core
 from repro.core import ref
+
+compile_cache.enable()
 
 # A clustered scene: two objects + clutter (the distribution Fractal's
 # shape-aware splits exploit).

@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from repro import scene
+from repro import compile_cache, scene
 from repro.data import synthetic
 
 
@@ -39,6 +39,7 @@ def main():
                          "host mesh (repro.dist)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     t0 = time.monotonic()
     coords, labels = synthetic.scene(args.seed, args.n)

@@ -12,7 +12,7 @@ Run:  PYTHONPATH=src python examples/serve_pnn.py \
 """
 import argparse
 
-from repro import serve
+from repro import compile_cache, serve
 from repro.data import synthetic
 
 
@@ -36,6 +36,7 @@ def main():
                          "mesh (repro.dist)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     buckets = tuple(int(b) for b in args.buckets.split(","))
     cfg = serve.ServeConfig(

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.data import synthetic
 from repro.models import pnn
 from repro.train import checkpoint as ckpt_lib
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = pnn.pointnet2_cls(n=args.n_points, point_ops=args.point_ops,
                             th=args.th)

@@ -19,7 +19,7 @@ Each op is split into a *plan* phase (window/quota/compaction index math,
 pure jnp here) and an *execute* phase (the distance / argmax / top-k inner
 loops), which dispatches through ``kernels/ops.py``: ``impl="xla"`` runs the
 jnp oracle (kernels/ref.py), ``impl="pallas"`` the TPU kernels
-(interpret=True off-TPU).  ``impl=None`` resolves from
+(interpreted on the CPU).  ``impl=None`` resolves from
 ``$REPRO_POINT_IMPL`` (default ``"xla"``).  Both backends are trainable:
 the execute ops carry custom VJPs (kernels/vjp.py) — gather differentiates
 in its features, the index producers stop gradients — so ``jax.grad``

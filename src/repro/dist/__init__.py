@@ -3,16 +3,7 @@
 * ``logical``     logical-axis sharding rules + the ``lc`` constraint helper
 * ``elastic``     degraded-device mesh selection
 * ``compression`` gradient codecs (bf16 / stochastic int8) + error feedback
-* ``compat``      jax-version shims for mesh construction
-
-Importing the package installs the jax compat shims (``AxisType`` and the
-``axis_types``-tolerant ``jax.make_mesh``) so call sites written against
-jax >= 0.5 run on the 0.4.x line too.
 """
-from repro.dist import compat
+from repro.dist import compression, elastic, logical
 
-compat.install()
-
-from repro.dist import compression, elastic, logical  # noqa: E402
-
-__all__ = ["compat", "compression", "elastic", "logical"]
+__all__ = ["compression", "elastic", "logical"]

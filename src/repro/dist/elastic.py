@@ -11,8 +11,7 @@ pre-compile the fallback meshes before they are needed.
 from __future__ import annotations
 
 import jax
-
-from repro.dist import compat
+from jax.sharding import AxisType
 
 HOST_SIZE = 8     # chips per host — the failure granularity
 POD_SIZE = 256    # chips per pod (v5e-256)
@@ -54,5 +53,5 @@ def make_mesh(*, model_axis: int = 2, devices=None):
     elastic analogue of launch/mesh.make_production_mesh)."""
     devices = jax.devices() if devices is None else list(devices)
     shape, names = choose_mesh_shape(len(devices), model_axis=model_axis)
-    return compat.make_mesh(shape, names, devices=devices,
-                            axis_types=(compat.AxisType.Auto,) * len(names))
+    return jax.make_mesh(shape, names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(names))

@@ -28,7 +28,7 @@ import contextlib
 import threading
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 # v0 baseline (docs/DESIGN.md §6): clouds/batch -> data axes, fractal leaves and
 # tensor-parallel dims -> model, params FSDP-sharded over data.  Key order
@@ -83,6 +83,8 @@ def _stack():
 
 
 def current() -> _Ctx | None:
+    """The innermost active binding; None outside any, or when
+    ``suspended``."""
     stack = _stack()
     return stack[-1] if stack else None
 
@@ -92,11 +94,36 @@ def logical_rules(mesh, rules):
     """Activate (mesh, rules) for ``lc`` / ``spec`` / ``axis_size``.
 
     Jitted functions must be *traced* inside the context (call them inside
-    the ``with`` block); the constraints are baked into the jaxpr."""
+    the ``with`` block); the constraints are baked into the jaxpr.
+
+    ``lc`` places values with ``with_sharding_constraint``, which only
+    accepts Auto mesh axes; a mesh with other axis types is refused here
+    rather than deep inside a trace."""
+    other = [n for n, t in zip(mesh.axis_names, mesh.axis_types)
+             if t != AxisType.Auto]
+    if other:
+        raise ValueError(
+            f"logical_rules needs a mesh whose axes are all Auto, as "
+            f"dist.elastic.make_mesh builds (jax.make_mesh(..., axis_types="
+            f"(AxisType.Auto,) * k)); axes {other} are not")
     stack = _stack()
     stack.append(_Ctx(mesh, rules))
     try:
         yield stack[-1]
+    finally:
+        stack.pop()
+
+
+@contextlib.contextmanager
+def suspended():
+    """Run without the active (mesh, rules): ``lc`` / ``spec`` /
+    ``axis_size`` act as outside any context.  For the body of a
+    ``shard_map``, where every mesh axis is manual and there is nothing
+    left for a sharding constraint to place."""
+    stack = _stack()
+    stack.append(None)
+    try:
+        yield
     finally:
         stack.pop()
 

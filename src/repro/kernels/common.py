@@ -1,18 +1,19 @@
 """Shared helpers for the FractalCloud Pallas TPU kernels.
 
-TPU notes (kernels are *targeted* at TPU v5e, validated in interpret mode):
+TPU notes (kernels compile for TPU v5e and run in interpret mode on CPU):
 
-* vectors are kept 2-D ``(1, L)`` / ``(R, L)`` with the large axis last so it
-  lands on the 128-wide lane dimension;
-* dynamic gathers inside VMEM are expressed as one-hot reductions/matmuls
-  (iota == idx), which lower to VPU selects / MXU dots instead of scatters;
+* every value stays 2-D — ``(1, L)`` rows, ``(R, 1)`` columns, ``(R, L)``
+  tiles — with the large axis last so it lands on the 128-wide lane
+  dimension; reductions keep their dims, and no scalar ever leaves the
+  vector unit;
+* arg-extrema are a max/min reduction followed by a min over the lanes
+  that attain it (the first extremum, as ``jnp.argmax``/``argmin`` pick);
+* dynamic gathers inside VMEM are one-hot selects/matmuls (iota == idx),
+  and per-slot outputs are accumulated with selects and stored once;
 * loop counts (k, num) are static and small, so selection loops unroll.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -21,43 +22,36 @@ NEG = -3.0e38
 INF = 3.0e38
 
 
-def row_iota(n: int, dtype=jnp.int32):
-    """(1, n) iota along lanes (TPU requires >=2D iota)."""
-    return lax.broadcasted_iota(dtype, (1, n), 1)
+def first_lane(hit, iot, n: int):
+    """Per row, the first lane where ``hit`` holds (``n`` if none) as an
+    ``(R, 1)`` column."""
+    return jnp.min(jnp.where(hit, iot, n), axis=-1, keepdims=True)
 
 
-def onehot_rows(idx, n: int, dtype=jnp.float32):
-    """idx (r,) -> (r, n) one-hot along lanes."""
-    iot = lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    return (iot == idx[:, None]).astype(dtype)
-
-
-def select_coord(coords, idx):
-    """coords (3, n), scalar idx -> (3,) gathered via one-hot reduction."""
-    oh = (lax.broadcasted_iota(jnp.int32, coords.shape, 1) == idx)
-    return jnp.sum(jnp.where(oh, coords, 0.0), axis=1)
-
-
-def sqdist_rows(a, b):
-    """a (3, r), b (3, n) -> (r, n) squared distances (expanded form so the
-    cross term is a (r,3)x(3,n) contraction)."""
-    a2 = jnp.sum(a * a, axis=0)[:, None]
-    b2 = jnp.sum(b * b, axis=0)[None, :]
-    cross = jnp.dot(a.T, b, preferred_element_type=jnp.float32)
-    return a2 + b2 - 2.0 * cross
+def sqdist_cols(a, b):
+    """a (r, 3) point rows, b (3, n) lane-major points -> (r, n) squared
+    distances, summed coordinate by coordinate (no cancellation, no MXU
+    rounding; ``kernels/ref.py`` evaluates the same sum in the same order)."""
+    d = None
+    for k in range(3):
+        diff = a[:, k:k + 1] - b[k:k + 1, :]
+        d = diff * diff if d is None else d + diff * diff
+    return d
 
 
 def argmin_extract(d, num: int):
-    """d (r, n): extract indices/values of the num smallest per row by
-    repeated masked min (the TPU analogue of the paper's merge-sort top-k
-    unit).  Returns (idx (r, num) i32, val (r, num))."""
+    """d (r, n): indices/values of the num smallest per row by repeated
+    masked min (the TPU analogue of the paper's merge-sort top-k unit).
+    Returns (idx (r, num) i32, val (r, num))."""
     r, n = d.shape
     iot = lax.broadcasted_iota(jnp.int32, (r, n), 1)
-    idxs, vals = [], []
-    for _ in range(num):
-        v = jnp.min(d, axis=1)
-        i = jnp.argmin(d, axis=1).astype(jnp.int32)
-        idxs.append(i)
-        vals.append(v)
-        d = jnp.where(iot == i[:, None], INF, d)
-    return jnp.stack(idxs, axis=1), jnp.stack(vals, axis=1)
+    slot = lax.broadcasted_iota(jnp.int32, (r, num), 1)
+    idx = jnp.zeros((r, num), jnp.int32)
+    val = jnp.zeros((r, num), d.dtype)
+    for j in range(num):
+        v = jnp.min(d, axis=1, keepdims=True)
+        i = first_lane(d == v, iot, n)
+        idx = jnp.where(slot == j, i, idx)
+        val = jnp.where(slot == j, v, val)
+        d = jnp.where(iot == i, INF, d)
+    return idx, val

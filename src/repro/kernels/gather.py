@@ -18,17 +18,20 @@ from jax.experimental import pallas as pl
 
 def _gather_kernel(feats_ref, idx_ref, out_ref):
     f = feats_ref[0]             # (W, C)
-    idx = idx_ref[0]             # (1, M) i32
+    idx = idx_ref[0]             # (M, 1) i32
     w = f.shape[0]
-    m = idx.shape[-1]
+    m = idx.shape[0]
     iot = lax.broadcasted_iota(jnp.int32, (m, w), 1)
-    onehot = (iot == idx[0][:, None]).astype(f.dtype)
-    out_ref[0] = jnp.dot(onehot, f, preferred_element_type=f.dtype)
+    onehot = (iot == idx).astype(f.dtype)
+    # HIGHEST: a one-hot fetch must return the features exactly, not
+    # rounded through a single bf16 MXU pass.
+    out_ref[0] = jnp.dot(onehot, f, preferred_element_type=f.dtype,
+                         precision=lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_blocks(window_feats: jax.Array, idx: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool) -> jax.Array:
     """window_feats (NB, W, C), idx (NB, M) local-to-window
     -> (NB, M, C) gathered features.  Out-of-range idx (negative or >= W)
     matches no one-hot row and fetches zeros."""
@@ -39,12 +42,12 @@ def gather_blocks(window_feats: jax.Array, idx: jax.Array, *,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, w, c), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, m), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, m, 1), lambda b: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, m, c), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, m, c), window_feats.dtype),
         interpret=interpret,
-    )(window_feats, idx.astype(jnp.int32)[:, None, :])
+    )(window_feats, idx.astype(jnp.int32)[:, :, None])
 
 
 def _scatter_add_kernel(g_ref, idx_ref, out_ref):
@@ -57,13 +60,14 @@ def _scatter_add_kernel(g_ref, idx_ref, out_ref):
     # contributes nothing — the scatter drops exactly what the gather
     # zero-filled.
     iot = lax.broadcasted_iota(jnp.int32, (w, m), 0)
-    onehot_t = (iot == idx[0][None, :]).astype(g.dtype)
-    out_ref[0] = jnp.dot(onehot_t, g, preferred_element_type=g.dtype)
+    onehot_t = (iot == idx).astype(g.dtype)
+    out_ref[0] = jnp.dot(onehot_t, g, preferred_element_type=g.dtype,
+                         precision=lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "interpret"))
 def scatter_add_blocks(g: jax.Array, idx: jax.Array, *, w: int,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """gather_blocks' backward: g (NB, M, C) cotangents, idx (NB, M)
     local-to-window -> (NB, W, C) scatter-added window cotangents.
 

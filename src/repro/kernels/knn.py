@@ -13,15 +13,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INF, argmin_extract, sqdist_rows
+from repro.kernels.common import INF, argmin_extract, sqdist_cols
 
 
 def _knn_kernel(q_ref, w_ref, wmask_ref, idx_ref, d2_ref, *, k: int):
-    q = q_ref[0]                 # (3, Q)
+    q = q_ref[0].T               # (Q, 3)
     w = w_ref[0]                 # (3, W)
     wm = wmask_ref[0] > 0        # (1, W)
-    d = sqdist_rows(q, w)        # (Q, W)
-    d = jnp.where(wm, d, INF)
+    d = jnp.where(wm, sqdist_cols(q, w), INF)       # (Q, W)
     idx, val = argmin_extract(d, k)
     idx_ref[0] = idx
     d2_ref[0] = val
@@ -29,7 +28,7 @@ def _knn_kernel(q_ref, w_ref, wmask_ref, idx_ref, d2_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def knn_blocks(queries: jax.Array, window: jax.Array, wmask: jax.Array, *,
-               k: int, interpret: bool = True):
+               k: int, interpret: bool):
     """queries (NB,3,Q), window (NB,3,W), wmask (NB,1,W)
     -> (idx (NB,Q,k) i32 local-to-window, d2 (NB,Q,k))."""
     nb, _, q = queries.shape

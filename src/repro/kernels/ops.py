@@ -2,7 +2,8 @@
 
 Each op accepts ``impl``:
 
-* ``"pallas"``    — the TPU kernel (interpret=True off-TPU, compiled on TPU);
+* ``"pallas"``    — the TPU kernel (compiled on TPU, interpreted on CPU;
+                    any other backend is an error — ``interpret_kernels``);
 * ``"xla"``       — the pure-jnp oracle (kernels/ref.py);
 * ``None``        — resolved from ``$REPRO_POINT_IMPL`` (default ``"pallas"``
                     here at the kernel layer; ``core/bppo.py`` defaults its
@@ -61,8 +62,15 @@ def resolve_impl(impl: str | None = None, default: str = "pallas") -> str:
     return impl
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_kernels() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU backend
+    (tests, small rehearsals) yes, on TPU never.  Any other backend is
+    refused rather than left quietly interpreting."""
+    backend = jax.default_backend()
+    if backend in ("cpu", "tpu"):
+        return backend == "cpu"
+    raise RuntimeError(f"Pallas kernels compile for TPU and interpret on "
+                       f"CPU; backend {backend!r} is neither")
 
 
 def _pad_lanes(x, axis, mult=LANE, value=0):
@@ -159,7 +167,7 @@ def _fps_blocks(coords, mask, *, k, impl, chunk):
     def run(coords, mask):
         c, m = _to_lane_major(coords, mask)
         if impl == "pallas":
-            return _fps.fps_blocks(c, m, k=k, interpret=not _on_tpu())
+            return _fps.fps_blocks(c, m, k=k, interpret=interpret_kernels())
         return _ref.fps_blocks(c, m, k=k)
 
     return _chunked(run, (coords, mask), chunk)
@@ -193,7 +201,7 @@ def _ball_query_blocks(centers, cmask, window, wmask, *, radius, num, impl,
         if impl == "pallas":
             idx, d2, cnt = _bq.ball_query_blocks(
                 c, cm, w, wm, radius=radius, num=num,
-                interpret=not _on_tpu())
+                interpret=interpret_kernels())
         else:
             idx, d2, cnt = _ref.ball_query_blocks(c, cm, w, wm,
                                                   radius=radius, num=num)
@@ -224,7 +232,7 @@ def _knn_blocks(queries, window, wmask, *, k, impl, chunk):
         w, wm = _to_lane_major(window, wmask)
         if impl == "pallas":
             idx, d2 = _knn.knn_blocks(q, w, wm, k=k,
-                                      interpret=not _on_tpu())
+                                      interpret=interpret_kernels())
         else:
             idx, d2 = _ref.knn_blocks(q, w, wm, k=k)
         return idx[:, :nq], d2[:, :nq]
@@ -258,7 +266,7 @@ def _gather_blocks(window_feats, idx, *, impl, chunk):
         if impl == "pallas":
             f = _pad_lanes(window_feats, -1)          # C on lanes
             f = _pad_lanes(f, -2, mult=8)             # W on sublanes
-            out = _ga.gather_blocks(f, idx, interpret=not _on_tpu())
+            out = _ga.gather_blocks(f, idx, interpret=interpret_kernels())
             return out[..., :c_out]
         return _ref.gather_blocks(window_feats, idx)
 
@@ -277,7 +285,7 @@ def _gather_grad_blocks(g, idx, *, w, impl, chunk):
             gg = _pad_lanes(gg, -2)                   # M: contraction dim,
             ii = _pad_lanes(idx, -1, value=-1)        # padded rows dropped
             out = _ga.scatter_add_blocks(gg, ii, w=w + (-w) % 8,
-                                         interpret=not _on_tpu())
+                                         interpret=interpret_kernels())
             return out[:, :w, :c_out]
         return _ref.scatter_add_blocks(g, idx, w=w)
 
@@ -307,7 +315,8 @@ def _fractal_level_blocks(coords, mask, mid, *, da, db, impl, chunk):
         c, m = _to_lane_major(coords, mask)
         if impl == "pallas":
             side, lcnt, stats = _fe.fractal_level_blocks(
-                c, m, mid[:, None], da=da, db=db, interpret=not _on_tpu())
+                c, m, mid[:, None], da=da, db=db,
+                interpret=interpret_kernels())
         else:
             side, lcnt, stats = _ref.fractal_level_blocks(
                 c, m, mid[:, None], da=da, db=db)

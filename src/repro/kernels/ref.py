@@ -24,8 +24,8 @@ def _fps_one(coords, vmask, k):
     bs = c.shape[-1]
 
     def d2_to(i):
-        diff = c - c[:, i][:, None]
-        return jnp.sum(diff * diff, axis=0)
+        sq = (c - c[:, i][:, None]) ** 2
+        return (sq[0] + sq[1]) + sq[2]          # the kernel's order
 
     start = jnp.argmax(v.astype(jnp.float32)).astype(jnp.int32)
     iot = jnp.arange(bs, dtype=jnp.int32)
@@ -63,9 +63,12 @@ def _topk_min(d, num):
 
 
 def _sqdist(a, b):
-    a2 = jnp.sum(a * a, axis=0)[:, None]
-    b2 = jnp.sum(b * b, axis=0)[None, :]
-    return a2 + b2 - 2.0 * (a.T @ b)
+    """a (3, r), b (3, n) -> (r, n) squared distances, summed coordinate
+    by coordinate as (dx^2 + dy^2) + dz^2 — the kernels' order, so the two
+    backends agree bit for bit."""
+    diff = a[:, :, None] - b[:, None, :]
+    sq = diff * diff
+    return (sq[0] + sq[1]) + sq[2]
 
 
 def ball_query_blocks(centers, cmask, window, wmask, *, radius, num):

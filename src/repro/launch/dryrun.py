@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this jits the real step function (train_step / prefill /
@@ -14,9 +11,13 @@ Usage:
   python -m repro.launch.dryrun --all --multi-pod --out results.json
   python -m repro.launch.dryrun --arch pointnext --shape pnn_289k  # PNN cell
   python -m repro.launch.dryrun --arch pointnext --shape pnn_33k --train
+
+The 512 placeholder devices are requested in ``main`` (``force_host_devices``),
+never on import, so importing this module leaves ``XLA_FLAGS`` alone.
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -273,7 +274,18 @@ def _stacked_cache_axes(cfg):
     return M.cache_axes(cfg)
 
 
+def force_host_devices(n: int = 512):
+    """Ask the CPU backend for ``n`` placeholder devices, keeping any flags
+    already in ``XLA_FLAGS``.  Takes effect only before JAX first touches
+    a device, so CLI ``main``s call it first thing."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
+
+
 def main(argv=None):
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -8,22 +8,12 @@ chips.  Elastic variants live in repro/dist/elastic.py.
 from __future__ import annotations
 
 import jax
-
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
-
-def make_host_mesh(model_axis: int = 2):
-    """Tiny mesh over whatever local devices exist (tests)."""
-    n = len(jax.devices())
-    model_axis = min(model_axis, n)
-    while n % model_axis:
-        model_axis -= 1
-    return make_mesh((n // model_axis, model_axis), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))

@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf hillclimb driver: re-run a dry-run cell under named variants and
 report the roofline-term deltas (hypothesis -> change -> before/after).
 
@@ -99,6 +96,8 @@ def run_variant(arch, shape, variant, multi_pod=False):
 
 
 def main(argv=None):
+    from repro.launch.dryrun import force_host_devices
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", required=True, help="arch:shape")
     ap.add_argument("--variants", default="baseline")

@@ -94,7 +94,7 @@ def run_pnn_cell(variant: str, shape_name: str, *, multi_pod: bool = False,
                                   jnp.float32)
 
     def serve_step(params, clouds):
-        return jax.vmap(lambda c: pnn.apply(params, cfg, c))(clouds)
+        return pnn.apply_batch(params, cfg, clouds, mesh=mesh)
 
     rules = rules or logical.RULES_V0
     batch_axes = rules.get("batch", ("pod", "data"))
@@ -118,7 +118,8 @@ def run_pnn_cell(variant: str, shape_name: str, *, multi_pod: bool = False,
                 mesh, P(batch_axes) if batch_axes else P())
             # The exact step the trainer runs (train/pnn.py), lowered with
             # the cell's shardings instead of its jit.
-            train_step = train_step_fn(cfg, opt_lib.OptConfig(warmup=0))
+            train_step = train_step_fn(cfg, opt_lib.OptConfig(warmup=0),
+                                       mesh)
             b_sh = {"points": cloud_sh, "labels": label_sh}
             lowered = jax.jit(
                 train_step, in_shardings=(None, None, b_sh),

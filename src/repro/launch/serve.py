@@ -15,7 +15,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.dist import elastic, logical
 from repro.lm import model as M
 
@@ -30,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--model-axis", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.lm_reduced(args.arch)
     if cfg.encoder_layers:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.dist import elastic, logical
 from repro.lm import model as M
 from repro.lm import steps as steps_lib
@@ -49,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--model-axis", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (configs.lm_config(args.arch) if args.full_config
            else configs.lm_reduced(args.arch))

@@ -10,7 +10,7 @@ Point operations are selectable:
 
 With ``point_ops="bppo"`` the execute phase of every point op additionally
 dispatches through the kernel backend selected by ``PNNConfig.impl``:
-``"xla"`` (jnp oracle) or ``"pallas"`` (TPU kernels, interpret off-TPU);
+``"xla"`` (jnp oracle) or ``"pallas"`` (TPU kernels, interpreted on CPU);
 ``None`` resolves from ``$REPRO_POINT_IMPL``.  Both backends differentiate
 (kernels/vjp.py), so either is valid under ``jax.grad`` — training no
 longer needs to wrap the model with ``impl="xla"``.  See docs/DESIGN.md §4
@@ -28,9 +28,12 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro import core
 from repro.core import ref
+from repro.dist import logical
+from repro.kernels import ops as kops
 
 Array = jax.Array
 
@@ -284,6 +287,57 @@ def apply(params, cfg: PNNConfig, coords: Array, feats: Array | None = None,
         up = _mlp(params["fp"][i], merged)
     h = _mlp(params["head"], up)
     return _dense(params["out"], h)
+
+
+def batch_spec(cfg: PNNConfig, mesh, batch: int):
+    """The ``PartitionSpec`` over which ``apply_batch`` splits ``batch``
+    clouds on ``mesh``, or None when it leaves placement to the logical
+    rules.
+
+    XLA cannot partition a Mosaic kernel, so the Pallas point ops on a
+    multi-device mesh run inside a ``shard_map``: each device runs the
+    whole model on its own clouds.  The clouds split over every mesh axis,
+    in mesh order, while the product of the axis sizes divides ``batch``
+    (callers size batches to the mesh; axes left out repeat the work)."""
+    if (mesh is None or mesh.devices.size == 1 or cfg.point_ops != "bppo"
+            or kops.resolve_impl(cfg.impl, default="xla") != "pallas"):
+        return None
+    axes, size = [], 1
+    for name, n in zip(mesh.axis_names, mesh.devices.shape):
+        if batch % (size * n) == 0:
+            axes.append(name)
+            size *= n
+    return P(tuple(axes))
+
+
+def apply_batch(params, cfg: PNNConfig, coords: Array, valid=None,
+                part0=None, mesh=None):
+    """``apply`` over a batch of clouds: coords (B, n, 3) -> (B, ...).
+
+    Without a ``batch_spec`` (no mesh, one device, or the xla point ops)
+    this is a vmap whose clouds sit on the ``batch`` logical axis, and the
+    ``lc`` constraints inside place the rest.  With one, the vmap runs
+    inside a ``shard_map`` over the spec, with the params replicated."""
+    if valid is None:
+        valid = jnp.ones(coords.shape[:2], bool)
+
+    def fwd(params, coords, valid, part0):
+        return jax.vmap(lambda c, v, p: apply(params, cfg, c, valid=v,
+                                              part0=p))(coords, valid, part0)
+
+    spec = batch_spec(cfg, mesh, coords.shape[0])
+    if spec is None:
+        coords = logical.lc(coords, "batch", "points", None)
+        valid = logical.lc(valid, "batch", "points")
+        return fwd(params, coords, valid, part0)
+
+    def body(params, coords, valid, part0):
+        with logical.suspended():
+            return fwd(params, coords, valid, part0)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), spec, spec, spec),
+                         out_specs=spec, check_vma=False)(
+                             params, coords, valid, part0)
 
 
 # Paper Table I model presets -------------------------------------------------

@@ -20,6 +20,7 @@ up front so reported latencies never include compile time.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import time
 
@@ -126,23 +127,19 @@ class ServeEngine:
     def _serve_fn(self, bucket: int):
         key = ("serve", bucket, self.impl)
         mcfg = self._model_cfg(bucket)
+        mesh = self.mesh
 
         if self.cfg.point_ops == "bppo":
             def build():
                 def step(params, clouds, valid, part):
-                    clouds = logical.lc(clouds, "batch", "points", None)
-                    valid = logical.lc(valid, "batch", "points")
-                    return jax.vmap(lambda c, v, p: pnn.apply(
-                        params, mcfg, c, valid=v, part0=p))(clouds, valid,
-                                                            part)
+                    return pnn.apply_batch(params, mcfg, clouds, valid,
+                                           part0=part, mesh=mesh)
                 return step
         else:
             def build():
                 def step(params, clouds, valid):
-                    clouds = logical.lc(clouds, "batch", "points", None)
-                    valid = logical.lc(valid, "batch", "points")
-                    return jax.vmap(lambda c, v: pnn.apply(
-                        params, mcfg, c, valid=v))(clouds, valid)
+                    return pnn.apply_batch(params, mcfg, clouds, valid,
+                                           mesh=mesh)
                 return step
 
         return self.plans.get(key, build)
@@ -157,9 +154,15 @@ class ServeEngine:
 
     def _device_put_batch(self, clouds, valid):
         """Shard one microbatch over the mesh: clouds -> the data axes,
-        specs fitted against actual shapes (non-dividing axes drop)."""
+        specs fitted against actual shapes (non-dividing axes drop) — or,
+        for the Pallas point ops, over the axes ``pnn.apply_batch`` splits
+        the clouds over."""
         if self.mesh is None:
             return clouds, valid
+        split = pnn.batch_spec(self._base, self.mesh, clouds.shape[0])
+        if split is not None:
+            sh = NamedSharding(self.mesh, split)
+            return jax.device_put((clouds, valid), (sh, sh))
         with logical.logical_rules(self.mesh, logical.RULES_V0):
             sh = (NamedSharding(self.mesh,
                                 logical.spec(("batch", "points", None))),
@@ -168,30 +171,79 @@ class ServeEngine:
         sh = logical.fit_specs(sh, (clouds, valid), self.mesh)
         return jax.device_put((clouds, valid), sh)
 
+    def _filler(self, bucket: int):
+        """One microbatch of all-invalid clouds — the filler ``_execute``
+        pads partial batches with.  (All-*valid* zeros would be ``bucket``
+        duplicate points: unsplittable, so warming on them would emit a
+        spurious partition-overflow warning.)"""
+        mb = self.queue.microbatch
+        return (jnp.zeros((mb, bucket, 3), jnp.float32),
+                jnp.zeros((mb, bucket), bool), jnp.zeros((mb,), jnp.int32))
+
+    def executable(self, bucket: int):
+        """The compiled serve executable of ``bucket`` (its HLO, cost and
+        memory analyses), lowered with the arguments placed as a
+        microbatch is.  After ``warm()`` this compiles nothing new."""
+        clouds, valid, dim0 = self._filler(bucket)
+        clouds, valid = self._device_put_batch(clouds, valid)
+        args = (self.params, clouds, valid)
+        if self.cfg.point_ops == "bppo":
+            plan = self._run(lambda *a: self._plan_fn(bucket).lower(*a),
+                             clouds, valid, dim0)
+            # The partition as the plan executable returns it, placement
+            # included, so the serve executable compiled here is the one
+            # forward() calls.
+            args += (jax.tree.map(
+                lambda o, sh: jax.ShapeDtypeStruct(o.shape, o.dtype,
+                                                   sharding=sh),
+                plan.out_info, plan.compile().output_shardings),)
+        return self._run(lambda *a: self._serve_fn(bucket).lower(*a)
+                         .compile(), *args)
+
     # -- serving ----------------------------------------------------------
 
     def warm(self, buckets=None) -> dict[int, float]:
-        """Compile the plan + serve executables per bucket (zero-filled
-        microbatch), so request latencies exclude compile.  Returns
-        {bucket: compile_seconds}."""
+        """Compile the plan + serve executables per bucket and run each
+        once on an all-invalid microbatch, so request latencies exclude
+        compile.  Returns {bucket: compile_seconds}.
+
+        The buckets compile on two threads, largest first (XLA compiles
+        without holding the GIL, so the compiles overlap on the host's
+        cores; the largest bucket's compile takes about as long as the
+        rest together, so a third thread would save little time and add a
+        third compile's host memory, ~5 GB for the default model); the
+        runs then reuse those executables one bucket at a time, so the
+        device never holds two buckets' temporaries."""
         # Compile timing is deliberately real wall time, not self._clock():
         # an injected logical clock cannot time actual XLA compile work,
         # and compile_s is reported separately from the request-latency
         # clock domain (stats() never mixes them).
-        for b in (buckets if buckets is not None else self.policy.buckets):
+        buckets = tuple(buckets if buckets is not None
+                        else self.policy.buckets)
+        # The matmul precision is thread-local: compile under the caller's.
+        precision = jax.config.jax_default_matmul_precision
+
+        def compile_one(b):
             t0 = time.monotonic()  # repolint: disable=CLK001
-            clouds = jnp.zeros((self.queue.microbatch, b, 3), jnp.float32)
-            # All-invalid clouds — the same filler _execute pads partial
-            # batches with.  (All-*valid* zeros would be b duplicate
-            # points: unsplittable, so every warm() would emit a spurious
-            # partition-overflow warning.)
-            valid = jnp.zeros((self.queue.microbatch, b), bool)
-            dim0 = jnp.zeros((self.queue.microbatch,), jnp.int32)
-            jax.block_until_ready(self._forward(b, clouds, valid, dim0))
-            self.compile_s[b] = time.monotonic() - t0  # repolint: disable=CLK001
+            with jax.default_matmul_precision(precision):
+                self.executable(b)
+            return time.monotonic() - t0  # repolint: disable=CLK001
+
+        order = sorted(buckets, reverse=True)
+        with concurrent.futures.ThreadPoolExecutor(2) as pool:
+            spent = dict(zip(order, pool.map(compile_one, order)))
+        for b in buckets:
+            t0 = time.monotonic()  # repolint: disable=CLK001
+            jax.block_until_ready(self.forward(b, *self._filler(b)))
+            run_s = time.monotonic() - t0  # repolint: disable=CLK001
+            self.compile_s[b] = spent[b] + run_s
         return dict(self.compile_s)
 
-    def _forward(self, bucket, clouds, valid, dim0):
+    def forward(self, bucket, clouds, valid, dim0):
+        """Run one microbatch — clouds (microbatch, bucket, 3), valid
+        (microbatch, bucket), dim0 (microbatch,) — through the bucket's
+        plan and serve executables; returns the logits as placed on the
+        device (sharded over the mesh, if any)."""
         clouds, valid = self._device_put_batch(clouds, valid)
         if self.cfg.point_ops == "bppo":
             part = self._run(self._plan_fn(bucket), clouds, valid, dim0)
@@ -253,7 +305,7 @@ class ServeEngine:
         valid = jnp.stack([r.valid for r in reqs]
                           + [jnp.zeros((bucket,), bool)] * npad)
         dim0 = jnp.asarray([r.dim0 for r in reqs] + [0] * npad, jnp.int32)
-        out = self._forward(bucket, clouds, valid, dim0)
+        out = self.forward(bucket, clouds, valid, dim0)
         jax.block_until_ready(out)
         t_done = self._clock() if now is None else now
         out = np.asarray(out)

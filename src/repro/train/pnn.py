@@ -12,7 +12,8 @@ monitor), ``data/synthetic.py`` (resumable counter-based batches), and
 shards like ``launch/train.py``: clouds -> the ``batch`` logical axis
 (``dist.logical.fit_specs``-fitted so non-dividing batch sizes drop),
 fractal leaves -> ``model`` via the ``lc`` constraints already inside
-``core/bppo.py``.
+``core/bppo.py``.  With the Pallas point ops the clouds split over the
+whole mesh instead (``models/pnn.apply_batch``).
 
 CLI (the CI train-smoke leg)::
 
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compile_cache
 from repro.data import synthetic
 from repro.dist import elastic, logical
 from repro.kernels import ops as kops
@@ -76,18 +78,19 @@ def model_config(cfg: TrainConfig) -> pnn.PNNConfig:
     return dataclasses.replace(mcfg, leaf_chunk=cfg.leaf_chunk)
 
 
-def loss_fn(params, mcfg: pnn.PNNConfig, batch):
+def loss_fn(params, mcfg: pnn.PNNConfig, batch, mesh=None):
     """Masked cross-entropy over a batch dict {points, labels[, valid]}.
 
     Returns (loss, aux) with aux = {"acc": ...} so the step metrics carry
-    a trainability signal alongside the loss."""
-    pts = logical.lc(batch["points"], "batch", "points", None)
+    a trainability signal alongside the loss.  ``mesh`` is the mesh the
+    step runs on (``pnn.apply_batch``: the Pallas point ops split the
+    clouds over it)."""
+    pts = batch["points"]
     labels = batch["labels"]
     valid = batch.get("valid")
     if valid is None:
         valid = jnp.ones(pts.shape[:2], bool)
-    logits = jax.vmap(lambda c, v: pnn.apply(params, mcfg, c, valid=v))(
-        pts, valid)
+    logits = pnn.apply_batch(params, mcfg, pts, valid, mesh=mesh)
     ll = jax.nn.log_softmax(logits)
     if mcfg.task == "cls":
         picked = jnp.take_along_axis(ll, labels[:, None], axis=-1)
@@ -102,7 +105,8 @@ def loss_fn(params, mcfg: pnn.PNNConfig, batch):
     return loss, {"acc": acc}
 
 
-def train_step_fn(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig):
+def train_step_fn(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig,
+                  mesh=None):
     """The raw (unjitted) fine-tune step: value_and_grad + AdamW update.
 
     Split out so callers that own their own jit (the dry-run train cell
@@ -111,7 +115,7 @@ def train_step_fn(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig):
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def step(params, opt_state, batch):
-        (loss, aux), grads = grad_fn(params, mcfg, batch)
+        (loss, aux), grads = grad_fn(params, mcfg, batch, mesh)
         params, opt_state, om = opt_lib.update(opt_cfg, grads, opt_state,
                                                params)
         return params, opt_state, {"loss": loss, **aux, **om}
@@ -119,16 +123,17 @@ def train_step_fn(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig):
     return step
 
 
-def make_train_step(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig):
+def make_train_step(mcfg: pnn.PNNConfig, opt_cfg: opt_lib.OptConfig,
+                    mesh=None):
     """One jitted AdamW step; ``return_grads=True`` hands raw grads back
     for the loop's gradient-compression / error-feedback path."""
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    step = jax.jit(train_step_fn(mcfg, opt_cfg))
+    step = jax.jit(train_step_fn(mcfg, opt_cfg, mesh))
 
     @jax.jit
     def grads_only(params, batch):
-        (loss, aux), grads = grad_fn(params, mcfg, batch)
+        (loss, aux), grads = grad_fn(params, mcfg, batch, mesh)
         return grads, {"loss": loss, **aux}
 
     def train_step(params, opt_state, batch, return_grads=False):
@@ -160,7 +165,8 @@ def fit(cfg: TrainConfig, params=None, log=print):
         p = pnn.init(jax.random.PRNGKey(cfg.seed), mcfg)
         if mesh is not None:
             # PNN params are small: replicate; the point-op leaves shard
-            # over "model" via bppo's lc constraints inside the step.
+            # over "model" via bppo's lc constraints inside the step (the
+            # clouds over the whole mesh, with the Pallas point ops).
             p = jax.device_put(p, jax.tree.map(
                 lambda _: NamedSharding(mesh, P()), p))
         return p
@@ -175,6 +181,9 @@ def fit(cfg: TrainConfig, params=None, log=print):
         batch = {"points": pts, "labels": labels}
         if mesh is None:
             return batch
+        split = pnn.batch_spec(mcfg, mesh, cfg.batch)
+        if split is not None:
+            return jax.device_put(batch, NamedSharding(mesh, split))
         with logical.logical_rules(mesh, rules):
             sh = {"points": NamedSharding(
                       mesh, logical.spec(("batch", "points", None))),
@@ -186,7 +195,7 @@ def fit(cfg: TrainConfig, params=None, log=print):
     opt_cfg = opt_lib.OptConfig(lr=cfg.lr, warmup=0,
                                 total_steps=max(cfg.steps, 1),
                                 weight_decay=cfg.weight_decay)
-    base = make_train_step(mcfg, opt_cfg)
+    base = make_train_step(mcfg, opt_cfg, mesh)
 
     def train_step(params, opt_state, batch, return_grads=False):
         if mesh is None:
@@ -224,6 +233,7 @@ def main(argv=None):
     ap.add_argument("--compression", default="none",
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = TrainConfig(preset=args.preset, n_points=args.n, th=args.th,
                       point_ops=args.point_ops, impl=args.impl,

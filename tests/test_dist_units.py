@@ -7,10 +7,16 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import logical
+from repro.dist import elastic, logical
 from repro.kernels import ops as kops
 
 jax.config.update("jax_platform_name", "cpu")
+
+
+def _auto_mesh():
+    """A (1, 1) ("data", "model") mesh with Auto axes, built the way the
+    program builds its own (``elastic.make_mesh``)."""
+    return elastic.make_mesh(devices=jax.devices()[:1])
 
 
 class TestLeafChunks:
@@ -64,14 +70,14 @@ class TestLogicalConstraint:
         assert logical.lc(x, "batch", "ff") is x
 
     def test_lc_inside_context_constrains(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         x = jnp.arange(24.0).reshape(4, 6)
         with logical.logical_rules(mesh, logical.RULES_V0):
             y = jax.jit(lambda v: logical.lc(v, "batch", "ff") * 1.0)(x)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
     def test_lc_rank_mismatch_raises(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         with logical.logical_rules(mesh, logical.RULES_V0):
             with pytest.raises(ValueError, match="rank"):
                 logical.lc(jnp.ones((2, 2)), "batch")
@@ -79,7 +85,7 @@ class TestLogicalConstraint:
     def test_priority_resolves_mesh_axis_conflicts(self):
         # seq_shard and heads both map to "model"; seq_shard has priority,
         # heads replicates (sequence-parallel v0 attention).
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         with logical.logical_rules(mesh, logical.RULES_V0):
             assert logical.spec(("batch", "seq_shard", "heads", None)) == \
                 P(("data",), "model", None, None)
@@ -87,7 +93,7 @@ class TestLogicalConstraint:
                 P(("data",), None, None, "model")
 
     def test_axis_size_and_rules_with(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         assert logical.axis_size("batch") == 1  # no context
         rules = logical.rules_with(points="model", ff=None)
         with logical.logical_rules(mesh, rules):
@@ -96,7 +102,7 @@ class TestLogicalConstraint:
             assert logical.axis_size("batch") == 1  # (1,1) mesh
 
     def test_nested_contexts_restore(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         with logical.logical_rules(mesh, logical.RULES_V0):
             with logical.logical_rules(mesh, logical.rules_with(ff=None)):
                 assert logical.spec(("ff",)) == P(None)

@@ -144,12 +144,36 @@ def test_scene_surfaces_tile_internal_overflow():
 # §10 exactness oracle: stitched tiles == whole-scene forward.
 # ---------------------------------------------------------------------------
 
+def _lattice_scene(seed, n, spacing=0.1):
+    """A jittered lattice of ``n`` (a power of two) points, shuffled.
+
+    Sites sit on a 2^a x 2^b x 2^c grid (a >= b >= c, round-robin over the
+    bits of n); each point moves by under a fifth of the spacing.  A
+    fractal split then falls between the two middle planes of the node
+    (the mid of the extremes is within 0.2 spacing of the plane gap, the
+    points within 0.2 of their planes), so every node halves exactly.
+    Coarse tiles and model leaves are therefore whole subtrees at fixed
+    depths log2(n / tile_points) and log2(n / th), every leaf's FPS quota
+    rate * th is whole (no rounding, so no budget truncation anywhere), and
+    every leaf sits log2(tile_points / th) levels below its tile — the two
+    exactness preconditions hold by construction, for any seed."""
+    bits = n.bit_length() - 1
+    assert 1 << bits == n
+    dims = [bits // 3 + (i < bits % 3) for i in range(3)]
+    axes = [np.arange(1 << d) for d in dims]
+    grid = np.stack(np.meshgrid(*axes, indexing="ij"), -1).reshape(-1, 3)
+    rng = np.random.default_rng(seed)
+    pts = grid + rng.uniform(-0.2, 0.2, grid.shape)
+    return (rng.permutation(pts) * spacing).astype(np.float32)
+
+
 def _check_exactness_preconditions(pts, *, n, tile_points, th, rate):
     """The two static-budget conditions under which tiling is exact (§10):
     the whole-scene run must not truncate sample quotas (k_out), and every
     model leaf must sit >= 2 levels below its tile node (so parent search
-    windows stay inside the tile).  Seeds in the tests are chosen to
-    satisfy both; assert so drift fails loudly."""
+    windows stay inside the tile).  ``_lattice_scene`` meets both by
+    construction, the clustered scene of the pallas case at its seed;
+    assert them so a change to the partition or the data fails loudly."""
     part_g = jax.jit(lambda p: core.partition(p, th=th))(pts)
     k_out = int(round(rate * n))
     samp = core.blockwise_fps(part_g, rate=rate, k_out=k_out, bs=th,
@@ -171,18 +195,25 @@ def _check_exactness_preconditions(pts, *, n, tile_points, th, rate):
         assert dg[inside].min() >= dc[i] + 2, f"tile at depth {dc[i]}"
 
 
-@pytest.mark.parametrize("impl,seed,n,tile_points", [
-    ("xla", 3, 4096, 1024),
-    ("pallas", 8, 2048, 512),      # interpret mode off-TPU
+@pytest.mark.parametrize("impl,seed,n,tile_points,data", [
+    # Every split of the lattice halves exactly: tiles at one depth.
+    pytest.param("xla", 3, 4096, 1024, "lattice", id="xla-3-4096-1024"),
+    # Clustered objects: an uneven tree whose tiles sit at several depths,
+    # so each tile's own dim0 matters (asserted below).  Interpret mode on
+    # the CPU.
+    pytest.param("pallas", 8, 2048, 512, "scene", id="pallas-8-2048-512"),
 ])
-def test_scene_matches_whole_forward(impl, seed, n, tile_points):
+def test_scene_matches_whole_forward(impl, seed, n, tile_points, data):
     """Acceptance oracle: halo=0 + single-SA-stage model + per-tile dim0
     -> stitched tile-wise logits match the direct whole-scene forward
     (same th/strategy/impl) to 1e-4 on owned points (all points: with
     halo=0 every tile row is owned)."""
     th = 64
     cfg = pnn.scene_seg(n=n, th=th, impl=impl, widths=(16, 16), fp=(16, 16))
-    pts_np, _ = synthetic.scene(seed, n, objects=n // 512)
+    if data == "lattice":
+        pts_np = _lattice_scene(seed, n)
+    else:
+        pts_np, _ = synthetic.scene(seed, n, objects=n // 512)
     pts = jnp.asarray(pts_np)
     _check_exactness_preconditions(pts, n=n, tile_points=tile_points, th=th,
                                    rate=cfg.stages[0].rate)
@@ -196,6 +227,8 @@ def test_scene_matches_whole_forward(impl, seed, n, tile_points):
     eng = scene.SceneEngine(scfg, params=params)
     out, plan = eng.infer(pts_np)
     assert plan.num_tiles >= 4
+    if data == "scene":
+        assert len({t.dim0 for t in plan.tiles}) >= 2, "tiles share one dim0"
     np.testing.assert_allclose(out, direct, atol=1e-4, rtol=1e-4)
     # every tile hit one of the two bucket executables, each traced once
     traces = eng.engine.plans.traces

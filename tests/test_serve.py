@@ -7,6 +7,11 @@ a mixed-size stream (trace counter), deadline flush of a partially filled
 microbatch, and mesh dispatch equal to the single-device path.
 """
 import dataclasses
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +79,18 @@ def test_scene_bucket_admission_minimal_fitting():
         assert int(v.sum()) == n and bool(v[:n].all())
 
 
+def untruncated_n_points(part, rate: float, n: int) -> int:
+    """The smallest ``n_points`` whose stage-0 sample budget
+    ``round(rate * n_points)`` holds every block-wise FPS quota of ``part``.
+
+    Each leaf's quota rounds ``rate * vsize`` by at most 1/2, so the quotas
+    of L leaves sum to at most ``rate * n + L / 2`` — which can exceed
+    ``round(rate * n)`` (per-leaf rounding adds up), truncating samples.
+    A budget at the bound never truncates, whatever the data."""
+    leaves = int(jnp.sum(part.is_leaf))
+    return int(np.ceil((rate * n + leaves / 2) / rate))
+
+
 @pytest.mark.parametrize("bucket,n", [(4096, 3000), (16384, 12000)])
 def test_padded_matches_unpadded_oracle_scene_buckets(bucket, n):
     """§9 padding invisibility at the scene-scale buckets (previously only
@@ -82,19 +99,24 @@ def test_padded_matches_unpadded_oracle_scene_buckets(bucket, n):
 
     Window placement keys on valid counts (window_view), so the large
     invalid tail cannot move search windows; the single-SA-stage model
-    bounds CPU cost; the seed satisfies the no-sample-truncation budget
-    of §9 (asserted below so data drift fails loudly)."""
+    bounds CPU cost.  §9 holds while no sample budget truncates, so the
+    unpadded oracle's budget is sized by the quota bound
+    (``untruncated_n_points``) and the bucket's budget is checked against
+    the same bound."""
     cfg = pnn.scene_seg(n=n, th=256, impl="xla", widths=(16, 16),
                         fp=(16, 16))
     params = pnn.init(jax.random.PRNGKey(0), cfg)
     pts = jnp.asarray(synthetic.scene(0, n)[0])
 
     from repro import core
+    rate = cfg.stages[0].rate
     part = jax.jit(lambda p: core.partition(p, th=256))(pts)
-    k_out = int(round(cfg.stages[0].rate * n))
-    samp = core.blockwise_fps(part, rate=cfg.stages[0].rate, k_out=k_out,
+    n_budget = untruncated_n_points(part, rate, n)
+    assert n_budget <= bucket, "bucket budget below the quota bound"
+    cfg = dataclasses.replace(cfg, n_points=n_budget)
+    samp = core.blockwise_fps(part, rate=rate, k_out=cfg.stage_sizes()[1],
                               bs=256, impl="xla")
-    assert int(samp.total) <= k_out, "seed no longer satisfies §9 budget"
+    assert int(samp.total) <= cfg.stage_sizes()[1]
 
     oracle = np.asarray(jax.jit(
         lambda c: pnn.apply(params, cfg, c))(pts))
@@ -321,3 +343,50 @@ def test_mesh_dispatch_matches_single_device():
     for rid in eng_s.results:
         np.testing.assert_allclose(eng_m.results[rid], eng_s.results[rid],
                                    rtol=1e-5, atol=1e-5)
+
+
+MESH_PALLAS_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import numpy as np
+    from repro import serve
+    from repro.data import synthetic
+
+    kw = dict(buckets=(64,), microbatch=4, max_wait_s=0.0,
+              variant="pointnet2", task="cls", th=32, impl="pallas")
+    eng_m = serve.ServeEngine(serve.ServeConfig(mesh="auto", **kw))
+    eng_s = serve.ServeEngine(serve.ServeConfig(**kw), params=eng_m.params)
+    for eng in (eng_m, eng_s):
+        for i, n in enumerate([40, 64, 50, 30, 60]):
+            eng.submit(synthetic.classification_batch(0, i, 1, n)[0][0])
+            eng.step()
+        eng.flush()
+    err = max(float(np.max(np.abs(eng_m.results[r] - eng_s.results[r])))
+              for r in eng_s.results)
+    out = eng_m.forward(64, *eng_m._filler(64))
+    shards = sorted((s.device.id, s.data.shape[0])
+                    for s in out.addressable_shards)
+    print(json.dumps({"n_dev": int(eng_m.mesh.devices.size), "err": err,
+                      "shards": shards, "batch": out.shape[0]}))
+""")
+
+
+def test_mesh_dispatch_pallas_matches_single_device():
+    """impl="pallas" on a multi-device mesh: XLA cannot partition a Mosaic
+    kernel, so each device runs the model on its own clouds inside a
+    shard_map (models/pnn.apply_batch) — every device holds one cloud of
+    the microbatch, and the mesh logits equal the single-device ones.
+    Four host devices, so a subprocess."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", MESH_PALLAS_PROG],
+                         capture_output=True, text=True, env=env,
+                         timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    data = json.loads(out.stdout.strip().splitlines()[-1])
+    assert data["n_dev"] == 4
+    assert data["err"] <= 1e-5
+    assert data["batch"] == 4
+    assert data["shards"] == [[d, 1] for d in range(4)]

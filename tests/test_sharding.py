@@ -11,14 +11,20 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist import logical
+from repro.dist import elastic, logical
 
 jax.config.update("jax_platform_name", "cpu")
 
 
+def _auto_mesh():
+    """A (1, 1) ("data", "model") mesh with Auto axes, built the way the
+    program builds its own (``elastic.make_mesh``)."""
+    return elastic.make_mesh(devices=jax.devices()[:1])
+
+
 class TestLogicalRules:
     def test_spec_mapping(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         with logical.logical_rules(mesh, logical.RULES_V0):
             assert logical.spec(("batch", None, "ff")) == \
                 P(("data",), None, "model")
@@ -27,13 +33,13 @@ class TestLogicalRules:
         assert logical.spec(("batch",)) == P()
 
     def test_missing_mesh_axis_dropped(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))  # no "pod"
+        mesh = _auto_mesh()  # no "pod"
         with logical.logical_rules(mesh, logical.RULES_V0):
             # "batch" -> ("pod","data") but pod is absent
             assert logical.spec(("batch",)) == P(("data",),)
 
     def test_param_specs_tree(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = _auto_mesh()
         axes = {"w": ("embed_fsdp", "ff"), "g": None,
                 "nested": {"e": ("experts", None, "ff")}}
         specs = logical.param_specs(axes, mesh)
