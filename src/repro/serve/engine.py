@@ -12,7 +12,11 @@ One engine owns the whole deployment path of docs/DESIGN.md §9:
   per request batch, mirroring the bppo plan/execute split (§4);
 * microbatches optionally shard over an elastic mesh via ``repro.dist``
   (``elastic.make_mesh`` + ``logical.fit_specs``): clouds -> ``data``,
-  fractal leaves -> ``model`` (§6).
+  fractal leaves -> ``model`` (§6);
+* each boundary is a host span (``spans``), recorded only while the
+  profiler runs: ``serve.admit`` per request, ``serve.execute`` per
+  microbatch with its children ``serve.assemble``, ``serve.plan``,
+  ``serve.forward``, ``serve.sync`` and ``serve.fetch``.
 
 The engine is synchronous and deterministic: time enters only through its
 clock (injectable for tests), and ``warm()`` compiles every executable
@@ -20,6 +24,7 @@ up front so reported latencies never include compile time.
 """
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import dataclasses
 import time
@@ -36,6 +41,10 @@ from repro.models import pnn
 from repro.serve.batching import MicroBatch, MicroBatchQueue
 from repro.serve.bucketing import DEFAULT_BUCKETS, BucketPolicy
 from repro.serve.plan_cache import PlanCache
+from repro.serve.spans import recording, span
+
+# Requests per bucket whose latencies stats() keeps for its percentiles.
+LATENCY_WINDOW = 65_536
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,7 +102,13 @@ class ServeEngine:
         self.params = (params if params is not None
                        else pnn.init(jax.random.PRNGKey(seed), self._base))
         self.results: dict[int, np.ndarray] = {}
-        self._lat: dict[int, list] = {b: [] for b in self.policy.buckets}
+        self._lat = {b: collections.deque(maxlen=LATENCY_WINDOW)
+                     for b in self.policy.buckets}
+        self._served = collections.Counter()   # bucket -> clouds answered
+        self._points = collections.Counter()   # bucket -> their points
+        # The plan of the last forward(), kept only while the profiler runs,
+        # for _execute's leaf counters.
+        self._traced_part = None
         self.compile_s: dict[int, float] = {}
         self._t_first: float | None = None
         self._t_last: float | None = None
@@ -245,11 +260,17 @@ class ServeEngine:
         plan and serve executables; returns the logits as placed on the
         device (sharded over the mesh, if any)."""
         clouds, valid = self._device_put_batch(clouds, valid)
-        if self.cfg.point_ops == "bppo":
+        if self.cfg.point_ops != "bppo":
+            with span("serve.forward"):
+                return self._run(self._serve_fn(bucket), self.params,
+                                 clouds, valid)
+        with span("serve.plan"):
             part = self._run(self._plan_fn(bucket), clouds, valid, dim0)
+        if recording():
+            self._traced_part = part
+        with span("serve.forward"):
             return self._run(self._serve_fn(bucket), self.params, clouds,
                              valid, part)
-        return self._run(self._serve_fn(bucket), self.params, clouds, valid)
 
     def submit(self, coords, now: float | None = None, dim0: int = 0) -> int:
         """Admit one (n, 3) cloud; returns the request id.
@@ -260,8 +281,10 @@ class ServeEngine:
         global one (docs/DESIGN.md §10).  It is a traced plan input, so it
         never grows the executable cache."""
         now = self._clock() if now is None else now
-        coords = jnp.asarray(coords, jnp.float32)
-        req = self.queue.submit(coords, now, dim0=dim0)
+        with span("serve.admit") as sp:
+            coords = jnp.asarray(coords, jnp.float32)
+            req = self.queue.submit(coords, now, dim0=dim0)
+            sp.set(rid=req.rid, bucket=req.bucket)
         if self._t_first is None:
             self._t_first = now
         return req.rid
@@ -272,16 +295,25 @@ class ServeEngine:
 
         An injected ``now`` is threaded through to completion stamping, so
         latencies stay in the caller's clock domain (see ``_execute``)."""
-        done = []
-        for mb in self.queue.ready(self._clock() if now is None else now):
-            done.extend(self._execute(mb, now=now))
-        return done
+        return self._execute_all(
+            self.queue.ready(self._clock() if now is None else now), now)
 
     def flush(self, now: float | None = None) -> list[int]:
         """Drain the queue (end of stream), deadline or not."""
+        return self._execute_all(self.queue.drain(), now)
+
+    def _execute_all(self, mbs: list, now: float | None) -> list[int]:
+        """Run microbatches in order.  The queue hands over every ready
+        microbatch at once, so the requests of a bucket still waiting for
+        dispatch are those of its later microbatches plus its pending
+        ones (``serve.execute``'s ``depth``)."""
+        behind = collections.Counter()
+        for mb in mbs:
+            behind[mb.bucket] += len(mb.requests)
         done = []
-        for mb in self.queue.drain():
-            done.extend(self._execute(mb, now=now))
+        for mb in mbs:
+            behind[mb.bucket] -= len(mb.requests)
+            done.extend(self._execute(mb, now, behind[mb.bucket]))
         return done
 
     def take(self, rid: int, default=None):
@@ -290,31 +322,57 @@ class ServeEngine:
         one array per request forever)."""
         return self.results.pop(rid, default)
 
-    def _execute(self, mb: MicroBatch, now: float | None = None) -> list[int]:
+    def _execute(self, mb: MicroBatch, now: float | None = None,
+                 behind: int = 0) -> list[int]:
         """Run one microbatch.  ``now`` is the caller-injected logical time
         (from ``step(now=)``/``flush(now=)``): when present, completions
         are stamped with it so latencies and ``wall_s`` never mix the
         injected clock domain with the engine's real clock; when absent,
         the engine clock is read *after* execution so real latencies
-        include the forward."""
+        include the forward.  ``behind`` counts the bucket's requests in
+        microbatches already taken from the queue after this one."""
         bucket, reqs = mb.bucket, mb.requests
         npad = self.queue.microbatch - len(reqs)
-        clouds = jnp.stack(
-            [r.coords for r in reqs]
-            + [jnp.zeros((bucket, 3), jnp.float32)] * npad)
-        valid = jnp.stack([r.valid for r in reqs]
-                          + [jnp.zeros((bucket,), bool)] * npad)
-        dim0 = jnp.asarray([r.dim0 for r in reqs] + [0] * npad, jnp.int32)
-        out = self.forward(bucket, clouds, valid, dim0)
-        jax.block_until_ready(out)
-        t_done = self._clock() if now is None else now
-        out = np.asarray(out)
-        rids = []
-        for i, r in enumerate(reqs):
-            res = out[i][:r.n] if self.cfg.task == "seg" else out[i]
-            self.results[r.rid] = res
-            self._lat[bucket].append((t_done - r.t_submit, r.n))
-            rids.append(r.rid)
+
+        def waited_ms():
+            t = self._clock() if now is None else now
+            return 1e3 * sum(t - r.t_submit for r in reqs)
+
+        with span("serve.execute",
+                  rids=lambda: " ".join(str(r.rid) for r in reqs),
+                  requests=len(reqs), slots=self.queue.microbatch,
+                  wait_ms=waited_ms,
+                  depth=lambda: behind + self.queue.pending(bucket)) as sp:
+            with span("serve.assemble"):
+                clouds = jnp.stack(
+                    [r.coords for r in reqs]
+                    + [jnp.zeros((bucket, 3), jnp.float32)] * npad)
+                valid = jnp.stack([r.valid for r in reqs]
+                                  + [jnp.zeros((bucket,), bool)] * npad)
+                dim0 = jnp.asarray([r.dim0 for r in reqs] + [0] * npad,
+                                   jnp.int32)
+            out = self.forward(bucket, clouds, valid, dim0)
+            with span("serve.sync"):
+                jax.block_until_ready(out)
+            t_done = self._clock() if now is None else now
+            part, self._traced_part = self._traced_part, None
+            if part is not None:
+                # Stage-0 leaves of the real clouds, against their slots;
+                # the plan has finished, so the fetch adds no sync.
+                n_real = len(reqs)
+                sp.set(leaves=lambda: int(
+                    np.asarray(part.num_leaves)[:n_real].sum()),
+                    leaf_slots=n_real * part.leaf_start.shape[-1])
+            with span("serve.fetch"):
+                out = np.asarray(out)
+                rids = []
+                for i, r in enumerate(reqs):
+                    res = out[i][:r.n] if self.cfg.task == "seg" else out[i]
+                    self.results[r.rid] = res
+                    self._lat[bucket].append((t_done - r.t_submit, r.n))
+                    self._served[bucket] += 1
+                    self._points[bucket] += r.n
+                    rids.append(r.rid)
         self._t_last = t_done
         return rids
 
@@ -323,6 +381,10 @@ class ServeEngine:
     def stats(self) -> dict:
         """Per-bucket latency percentiles + sustained throughput + plan
         cache counters (the BENCH_serve.json payload).
+
+        ``count`` and the throughput count every request answered; the
+        percentiles and ``mean_ms`` cover each bucket's most recent
+        ``LATENCY_WINDOW`` (65,536) requests.
 
         Throughput (``wall_s``, ``clouds_per_s``, ``mpts_per_s``) is
         ``None`` until at least one microbatch has completed *and* the
@@ -333,7 +395,8 @@ class ServeEngine:
         instead of "unknown" (benchmarks/serve_bench.py skips the None
         rows)."""
         buckets = {}
-        served, points = 0, 0
+        served = sum(self._served.values())
+        points = sum(self._points.values())
         wall = None
         if (self._t_first is not None and self._t_last is not None
                 and self._t_last > self._t_first):
@@ -342,16 +405,14 @@ class ServeEngine:
             if not lat:
                 continue
             ls = np.asarray([l for l, _ in lat])
-            pts = int(sum(n for _, n in lat))
-            served += len(ls)
-            points += pts
+            count = self._served[b]
             buckets[b] = {
-                "count": len(ls),
+                "count": count,
                 "p50_ms": float(np.percentile(ls, 50) * 1e3),
                 "p95_ms": float(np.percentile(ls, 95) * 1e3),
                 "p99_ms": float(np.percentile(ls, 99) * 1e3),
                 "mean_ms": float(ls.mean() * 1e3),
-                "clouds_per_s": len(ls) / wall if wall is not None else None,
+                "clouds_per_s": count / wall if wall is not None else None,
                 "compile_s": self.compile_s.get(b),
             }
         return {"impl": self.impl, "served": served, "wall_s": wall,

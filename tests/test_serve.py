@@ -327,6 +327,28 @@ def test_throughput_none_until_first_completion():
     assert st["buckets"][64]["clouds_per_s"] == st["clouds_per_s"]
 
 
+def test_latency_window_keeps_the_most_recent(monkeypatch):
+    """stats()' percentiles cover each bucket's most recent
+    LATENCY_WINDOW requests, so a long-lived engine's latency record is
+    bounded; the counts and the throughput still cover every request."""
+    from repro.serve import engine as serve_engine
+    monkeypatch.setattr(serve_engine, "LATENCY_WINDOW", 2)
+    cfg = serve.ServeConfig(buckets=(64,), microbatch=1, max_wait_s=60.0,
+                            variant="pointnet2", task="cls", th=32,
+                            impl="xla")
+    eng = serve.ServeEngine(cfg, clock=FakeClock())
+    for i, (t, lat) in enumerate([(1.0, 0.5), (2.0, 1.0), (3.0, 1.5)]):
+        eng.submit(cloud(40, i), now=t)
+        assert len(eng.step(now=t + lat)) == 1
+    assert [l for l, _ in eng._lat[64]] == [1.0, 1.5]
+    st = eng.stats()
+    assert st["served"] == 3 and st["buckets"][64]["count"] == 3
+    assert st["buckets"][64]["p50_ms"] == pytest.approx(1.25e3)
+    assert st["wall_s"] == pytest.approx(3.5)          # 4.5 - 1.0
+    assert st["clouds_per_s"] == pytest.approx(3 / 3.5)
+    assert st["mpts_per_s"] == pytest.approx(3 * 40 / 3.5 / 1e6)
+
+
 def test_mesh_dispatch_matches_single_device():
     """mesh="auto" (elastic mesh over host devices, fit_specs-fitted
     microbatch sharding) returns the same logits as the mesh-free path."""
