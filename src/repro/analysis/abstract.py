@@ -114,6 +114,7 @@ def build_cases() -> tuple:
 
     from repro.kernels import ops as kops
     from repro.kernels import ref as _ref
+    from repro.kernels.common import leaves_per_step
 
     f32, i32 = jnp.float32, jnp.int32
 
@@ -126,6 +127,18 @@ def build_cases() -> tuple:
 
     def ev(fn, *args, **kw):
         return jax.eval_shape(functools.partial(fn, **kw), *args)
+
+    def search_tiles(d, name, rows, out):
+        """A neighbour search's tiles: query rows padded to 8 sublanes, G
+        leaves per grid step, leaves padded to a multiple of G."""
+        r = _pad(rows, SUBLANE)
+        g = leaves_per_step(r, d["nb"])
+        nb, w = _pad(d["nb"], g), _pad(d["w"], LANE)
+        return [Tile(name, (nb, 3, r), (g, 3, r)),
+                Tile("window", (nb, 3, w), (g, 3, w)),
+                Tile("d2_matrix", (g * r, w), (g * r, w), ref=False),
+                Tile("idx_out", (nb, r, out), (g, r, out)),
+                Tile("d2_out", (nb, r, out), (g, r, out))]
 
     cases = []
 
@@ -162,18 +175,7 @@ def build_cases() -> tuple:
             aval((d["nb"], 3, d["k"])), aval((d["nb"], 1, d["k"])),
             aval((d["nb"], 3, d["w"])), aval((d["nb"], 1, d["w"])),
             radius=0.3, num=d["num"]),
-        tiles=lambda d: [
-            Tile("centers", (d["nb"], 3, _pad(d["k"], LANE)),
-                 (1, 3, _pad(d["k"], LANE))),
-            Tile("window", (d["nb"], 3, _pad(d["w"], LANE)),
-                 (1, 3, _pad(d["w"], LANE))),
-            Tile("d2_matrix", (_pad(d["k"], LANE), _pad(d["w"], LANE)),
-                 (_pad(d["k"], LANE), _pad(d["w"], LANE)), ref=False),
-            Tile("idx_out", (d["nb"], _pad(d["k"], LANE), d["num"]),
-                 (1, _pad(d["k"], LANE), d["num"])),
-            Tile("d2_out", (d["nb"], _pad(d["k"], LANE), d["num"]),
-                 (1, _pad(d["k"], LANE), d["num"])),
-        ]))
+        tiles=lambda d: search_tiles(d, "centers", d["k"], d["num"])))
 
     # knn_blocks -----------------------------------------------------------
     cases.append(OpCase(
@@ -187,14 +189,7 @@ def build_cases() -> tuple:
             _ref.knn_blocks,
             aval((d["nb"], 3, d["m"])),
             aval((d["nb"], 3, d["w"])), aval((d["nb"], 1, d["w"])), k=3),
-        tiles=lambda d: [
-            Tile("queries", (d["nb"], 3, _pad(d["m"], LANE)),
-                 (1, 3, _pad(d["m"], LANE))),
-            Tile("window", (d["nb"], 3, _pad(d["w"], LANE)),
-                 (1, 3, _pad(d["w"], LANE))),
-            Tile("d2_matrix", (_pad(d["m"], LANE), _pad(d["w"], LANE)),
-                 (_pad(d["m"], LANE), _pad(d["w"], LANE)), ref=False),
-        ]))
+        tiles=lambda d: search_tiles(d, "queries", d["m"], 3)))
 
     # gather_blocks (forward + its scatter-add backward tiles) -------------
     cases.append(OpCase(

@@ -10,7 +10,9 @@ TPU notes (kernels compile for TPU v5e and run in interpret mode on CPU):
   that attain it (the first extremum, as ``jnp.argmax``/``argmin`` pick);
 * dynamic gathers inside VMEM are one-hot selects/matmuls (iota == idx),
   and per-slot outputs are accumulated with selects and stored once;
-* loop counts (k, num) are static and small, so selection loops unroll.
+* loop counts (k, num) are static and small, so selection loops unroll;
+* the neighbour searches (ball query, kNN) pack the query rows of several
+  leaves into one grid step's 128-row distance tile (``leaves_per_step``).
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ from jax import lax
 # Plain Python floats: Pallas kernel bodies may not capture device constants.
 NEG = -3.0e38
 INF = 3.0e38
+TILE_ROWS = 128     # rows of a neighbour search's distance tile per grid step
 
 
 def first_lane(hit, iot, n: int):
@@ -29,14 +32,30 @@ def first_lane(hit, iot, n: int):
 
 
 def sqdist_cols(a, b):
-    """a (r, 3) point rows, b (3, n) lane-major points -> (r, n) squared
-    distances, summed coordinate by coordinate (no cancellation, no MXU
-    rounding; ``kernels/ref.py`` evaluates the same sum in the same order)."""
+    """a (..., r, 3) point rows, b (..., 3, n) lane-major points ->
+    (..., r, n) squared distances, summed coordinate by coordinate (no
+    cancellation, no MXU rounding; ``kernels/ref.py`` evaluates the same
+    sum in the same order)."""
     d = None
     for k in range(3):
-        diff = a[:, k:k + 1] - b[k:k + 1, :]
+        diff = a[..., k:k + 1] - b[..., k:k + 1, :]
         d = diff * diff if d is None else d + diff * diff
     return d
+
+
+def leaves_per_step(rows: int, nb: int) -> int:
+    """Leaves that share one neighbour-search grid step: as many blocks of
+    ``rows`` query rows (a multiple of 8) as fill a ``TILE_ROWS``-row
+    distance tile, at most ``nb`` and at least one."""
+    return max(1, min(TILE_ROWS // rows, nb))
+
+
+def pad_leaves(g: int, *arrays):
+    """Pad the leading (leaf) axis of each array to a multiple of ``g`` with
+    zeros: the padded leaves' masks are 0, so they select nothing."""
+    pad = (-arrays[0].shape[0]) % g
+    return tuple(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                 for a in arrays)
 
 
 def argmin_extract(d, num: int):

@@ -14,7 +14,12 @@ it ad hoc (docs/DESIGN.md §4):
 
 * *layout* — user-facing tensors are (NB, BS, 3) / (NB, BS); kernels consume
   lane-major (NB, 3, BS') with BS' padded to the 128-lane boundary (padded
-  lanes masked invalid) and results sliced back to caller shapes;
+  lanes masked invalid) and results sliced back to caller shapes.  The
+  neighbour searches' query rows (ball-query centers, kNN queries) are
+  padded only to R, a multiple of 8 sublanes, and G =
+  ``kernels.common.leaves_per_step(R, NB)`` leaves (128 // R, at most NB,
+  at least 1) share one grid step's 128-row distance tile — the kernel
+  pads NB to a multiple of G with masked leaves;
 * *leaf-chunking* — every op takes ``chunk``: the block axis is processed
   ``chunk`` blocks per ``lax.map`` step, bounding the live distance /
   gather-tile footprint at large scale (``leaf_chunks`` is the shared
@@ -50,6 +55,7 @@ from repro.kernels import ref as _ref
 from repro.kernels import vjp as _vjp
 
 LANE = 128
+SUBLANE = 8
 IMPLS = ("xla", "pallas")
 
 
@@ -83,10 +89,11 @@ def _pad_lanes(x, axis, mult=LANE, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def _to_lane_major(coords, mask):
-    """(NB, BS, 3), (NB, BS) -> (NB, 3, BS'), (NB, 1, BS')."""
-    c = _pad_lanes(jnp.swapaxes(coords, -1, -2), -1)
-    m = _pad_lanes(mask.astype(jnp.float32)[:, None, :], -1)
+def _to_lane_major(coords, mask, mult=LANE):
+    """(NB, BS, 3), (NB, BS) -> (NB, 3, BS'), (NB, 1, BS'), BS' the next
+    multiple of ``mult``."""
+    c = _pad_lanes(jnp.swapaxes(coords, -1, -2), -1, mult)
+    m = _pad_lanes(mask.astype(jnp.float32)[:, None, :], -1, mult)
     return c, m
 
 
@@ -196,7 +203,7 @@ def _ball_query_blocks(centers, cmask, window, wmask, *, radius, num, impl,
     kc = centers.shape[1]
 
     def run(centers, cmask, window, wmask):
-        c, cm = _to_lane_major(centers, cmask)
+        c, cm = _to_lane_major(centers, cmask, SUBLANE)
         w, wm = _to_lane_major(window, wmask)
         if impl == "pallas":
             idx, d2, cnt = _bq.ball_query_blocks(
@@ -228,7 +235,8 @@ def _knn_blocks(queries, window, wmask, *, k, impl, chunk):
     nq = queries.shape[1]
 
     def run(queries, window, wmask):
-        q, _ = _to_lane_major(queries, jnp.ones(queries.shape[:2], bool))
+        q, _ = _to_lane_major(queries, jnp.ones(queries.shape[:2], bool),
+                              SUBLANE)
         w, wm = _to_lane_major(window, wmask)
         if impl == "pallas":
             idx, d2 = _knn.knn_blocks(q, w, wm, k=k,

@@ -48,22 +48,29 @@ def test_fps_kernel_samples_valid_first():
         assert len(np.unique(idx[b][:take])) == take, "duplicate sample"
 
 
+# kc 5 / 9 pad to 8 / 16 rows, so 16 / 8 leaves share a grid step; 13
+# leaves is no multiple of either, and the middle leaf's centers are masked.
 @pytest.mark.parametrize("nb,kc,w,num", [(3, 16, 128, 8), (2, 32, 256, 16),
-                                         (5, 8, 64, 4), (1, 64, 512, 32)])
+                                         (5, 8, 64, 4), (1, 64, 512, 32),
+                                         (13, 5, 64, 32), (13, 9, 64, 32),
+                                         (13, 9, 200, 16), (17, 65, 96, 8)])
 def test_ball_query_kernel_matches_ref(nb, kc, w, num):
     rng = np.random.default_rng(3)
     win, wmask = blocks(4, nb, w)
     ci = rng.integers(0, w, (nb, kc))
     centers = jnp.take_along_axis(win, jnp.asarray(ci)[..., None], axis=1)
     cmask = jnp.ones((nb, kc), bool)
+    if nb > 1:
+        cmask = cmask.at[nb // 2].set(False)
     a_idx, a_d2, a_cnt = ops.ball_query_blocks(
         centers, cmask, win, wmask, radius=0.7, num=num, impl="pallas")
     b_idx, b_d2, b_cnt = ops.ball_query_blocks(
         centers, cmask, win, wmask, radius=0.7, num=num, impl="xla")
     np.testing.assert_array_equal(np.asarray(a_idx), np.asarray(b_idx))
-    np.testing.assert_allclose(np.asarray(a_d2), np.asarray(b_d2),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(a_d2), np.asarray(b_d2))
     np.testing.assert_array_equal(np.asarray(a_cnt), np.asarray(b_cnt))
+    if nb > 1:
+        assert not np.asarray(a_cnt)[nb // 2].any()
 
 
 def test_ball_query_semantics():
@@ -87,16 +94,20 @@ def test_ball_query_semantics():
             np.testing.assert_array_equal(got[:valid_k], order[:valid_k])
 
 
+# q 32 packs 4 leaves per grid step (13 leaves: no multiple of 4), q 5
+# packs 16; the middle leaf's window is empty.
 @pytest.mark.parametrize("nb,q,w,k", [(3, 32, 128, 3), (2, 64, 96, 5),
-                                      (1, 16, 256, 8)])
+                                      (1, 16, 256, 8), (13, 32, 24, 3),
+                                      (13, 5, 40, 3)])
 def test_knn_kernel_matches_ref(nb, q, w, k):
     win, wmask = blocks(7, nb, w)
     queries, _ = blocks(8, nb, q)
+    if nb > 1:
+        wmask = wmask.at[nb // 2].set(False)
     a_idx, a_d2 = ops.knn_blocks(queries, win, wmask, k=k, impl="pallas")
     b_idx, b_d2 = ops.knn_blocks(queries, win, wmask, k=k, impl="xla")
     np.testing.assert_array_equal(np.asarray(a_idx), np.asarray(b_idx))
-    np.testing.assert_allclose(np.asarray(a_d2), np.asarray(b_d2),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(a_d2), np.asarray(b_d2))
 
 
 @pytest.mark.parametrize("nb,w,c,m", [(3, 64, 16, 20), (2, 128, 32, 64),

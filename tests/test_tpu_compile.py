@@ -8,17 +8,23 @@ sizes the default serving config hands it (th=256, the ``PNNConfig``
 stages), vmapped over a microbatch as the serve executable runs it, and the
 executable must hold a Mosaic kernel (``tpu_custom_call``).
 
+The neighbour searches' layout is checked at the benchmark's sizes too:
+query rows padded to a multiple of 8, so the custom call's centre operand
+ends in (3, R) and its index result in (R, num) — the shapes the
+benchmark's roofline reads — while several leaves share each grid step.
+
 The topology is described inside a fixture, never on import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ball_query, fps, fractal_engine, gather, knn
+from repro.kernels import ball_query, fps, fractal_engine, gather, knn, ops
 from repro.models import pnn
 from repro.serve import ServeConfig
 
@@ -49,12 +55,12 @@ def topo():
 @pytest.fixture(scope="module")
 def sizes():
     """Block sizes of the default serving path (kernels/ops.py pads point
-    axes to 128 lanes and window rows to 8 sublanes)."""
+    axes to 128 lanes, ball-query centers and window rows to 8 sublanes)."""
     th = ServeConfig().th
     stage = pnn.PNNConfig().stages[0]
     lanes = lambda n: -(-n // 128) * 128  # noqa: E731
     k = int(round(stage.rate * th)) + 1
-    return {"bs": th, "k": k, "kc": lanes(k), "w": 2 * th,
+    return {"bs": th, "k": k, "kc": -(-k // 8) * 8, "w": 2 * th,
             "num": stage.nsample, "radius": stage.radius,
             "wc": lanes(max(16, int(2 * th * stage.rate))),
             "m": 3 * th, "c": lanes(max(max(s.widths) for s in
@@ -102,3 +108,60 @@ def test_kernel_compiles_for_v5e(topo, sizes, name):
     fn, *shapes = CASES[name](sizes)
     compiled = _compile(topo, fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The benchmark's stage-1 neighbour searches: S3DIS ball query (9 centers
+# per leaf, 1,244 leaf slots, microbatch 4), ScanNet's (5 centers, 2,488
+# slots, batch 16) and the first FP stage's kNN (32 points of a fine leaf).
+LAYOUT = {
+    "ball_query_s3dis": dict(mb=4, nb=1244, rows=9, w=64, num=32, r=16),
+    "ball_query_scannet": dict(mb=16, nb=2488, rows=5, w=64, num=32, r=8),
+    "knn_fp": dict(mb=4, nb=1244, rows=32, w=16, num=3, r=32),
+}
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The wrappers compile their kernels, not the interpreter the CPU
+    backend would pick; the traces made so are dropped afterwards."""
+    monkeypatch.setattr(ops, "interpret_kernels", lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _custom_call_shapes(text):
+    """(result shapes, operand shapes) of the one tpu_custom_call."""
+    shape = lambda t: [tuple(int(x) for x in m.split(",") if x)  # noqa: E731
+                       for m in re.findall(r"(?:f32|s32)\[([0-9,]*)\]", t)]
+    [line] = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    results = line.split("custom-call(")[0]
+    operands = line.split("operand_layout_constraints={")[1].split("}}")[0]
+    return shape(results), shape(operands)
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUT))
+def test_neighbour_search_packs_rows(topo, compiled_kernels, name):
+    z = LAYOUT[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    lead = (z["mb"], z["nb"])
+
+    def arg(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=one_chip)
+
+    rows, win = arg((z["rows"], 3)), arg((z["w"], 3))
+    rmask, wmask = arg((z["rows"],), jnp.bool_), arg((z["w"],), jnp.bool_)
+    if name.startswith("ball_query"):
+        fn = lambda c, cm, w, wm: ops.ball_query_blocks(  # noqa: E731
+            c, cm, w, wm, radius=0.1, num=z["num"], impl="pallas")
+        args = (rows, rmask, win, wmask)
+    else:
+        fn = lambda q, w, wm: ops.knn_blocks(  # noqa: E731
+            q, w, wm, k=z["num"], impl="pallas")
+        args = (rows, win, wmask)
+    compiled = jax.jit(jax.vmap(fn)).lower(*args).compile()
+    results, operands = _custom_call_shapes(compiled.as_text())
+    r, g = z["r"], 128 // z["r"]
+    assert operands[0][-2:] == (3, r)
+    assert results[0][-2:] == (r, z["num"])
+    assert operands[0][:2] == (z["mb"], -(-z["nb"] // g) * g)
