@@ -15,11 +15,11 @@ it ad hoc (docs/DESIGN.md §4):
 * *layout* — user-facing tensors are (NB, BS, 3) / (NB, BS); kernels consume
   lane-major (NB, 3, BS') with BS' padded to the 128-lane boundary (padded
   lanes masked invalid) and results sliced back to caller shapes.  The
-  neighbour searches' query rows (ball-query centers, kNN queries) are
-  padded only to R, a multiple of 8 sublanes, and G =
-  ``kernels.common.leaves_per_step(R, NB)`` leaves (128 // R, at most NB,
-  at least 1) share one grid step's 128-row distance tile — the kernel
-  pads NB to a multiple of G with masked leaves;
+  neighbour-search kernels' query rows (ball-query centers, kNN queries)
+  are padded only to R, a multiple of 8 sublanes (the xla path keeps 128
+  lanes), and G = ``kernels.common.leaves_per_step(R, NB)`` leaves (128 //
+  R, at most NB, at least 1) share one grid step's 128-row distance tile —
+  the kernel pads NB to a multiple of G with masked leaves;
 * *leaf-chunking* — every op takes ``chunk``: the block axis is processed
   ``chunk`` blocks per ``lax.map`` step, bounding the live distance /
   gather-tile footprint at large scale (``leaf_chunks`` is the shared
@@ -95,6 +95,13 @@ def _to_lane_major(coords, mask, mult=LANE):
     c = _pad_lanes(jnp.swapaxes(coords, -1, -2), -1, mult)
     m = _pad_lanes(mask.astype(jnp.float32)[:, None, :], -1, mult)
     return c, m
+
+
+def _query_rows(impl: str) -> int:
+    """The multiple a neighbour search pads its query axis to: 8 sublanes
+    for the kernels, which pack several leaves per grid step; the xla path
+    keeps its 128 lanes."""
+    return SUBLANE if impl == "pallas" else LANE
 
 
 def pad_points(coords, n: int, valid=None):
@@ -203,7 +210,7 @@ def _ball_query_blocks(centers, cmask, window, wmask, *, radius, num, impl,
     kc = centers.shape[1]
 
     def run(centers, cmask, window, wmask):
-        c, cm = _to_lane_major(centers, cmask, SUBLANE)
+        c, cm = _to_lane_major(centers, cmask, _query_rows(impl))
         w, wm = _to_lane_major(window, wmask)
         if impl == "pallas":
             idx, d2, cnt = _bq.ball_query_blocks(
@@ -236,7 +243,7 @@ def _knn_blocks(queries, window, wmask, *, k, impl, chunk):
 
     def run(queries, window, wmask):
         q, _ = _to_lane_major(queries, jnp.ones(queries.shape[:2], bool),
-                              SUBLANE)
+                              _query_rows(impl))
         w, wm = _to_lane_major(window, wmask)
         if impl == "pallas":
             idx, d2 = _knn.knn_blocks(q, w, wm, k=k,
